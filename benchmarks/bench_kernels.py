@@ -2,8 +2,7 @@
 numpy host path — correctness + CPU-side call timing.
 
 Interpret-mode timings are *functional* only (the kernels target TPU v5e);
-the derived column reports bytes-moved so the VMEM-roofline expectation
-(tile bytes / 819 GB/s) can be compared on real hardware."""
+they say nothing about device speed."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -28,10 +27,8 @@ def run(quick: bool = False):
         ref.lorenzo3d_codes_ref(x, eb, tile=shape)), repeat=3)
     _, t_np = timed(lambda: sz.lorenzo_nd_codes(
         sz.prequant(np.asarray(x), eb)), repeat=3)
-    nbytes = x.size * 4 + x.size * 4
     rows.append(("lorenzo3d_codes", round(t_k * 1e6, 1),
-                 round(t_r * 1e6, 1), round(t_np * 1e6, 1),
-                 round(nbytes / 819e9 * 1e6, 3)))
+                 round(t_r * 1e6, 1), round(t_np * 1e6, 1)))
 
     codes_i = jnp.asarray(np.random.default_rng(1)
                           .integers(0, 1024, size=(65536,)), jnp.int32)
@@ -40,7 +37,7 @@ def run(quick: bool = False):
     _, t_r = timed(lambda: ref.hist_ref(codes_i, 1024).block_until_ready(),
                    repeat=3)
     rows.append(("hist_1024", round(t_k * 1e6, 1), round(t_r * 1e6, 1),
-                 "-", round(codes_i.size * 4 / 819e9 * 1e6, 3)))
+                 "-"))
 
     g = jnp.asarray(np.random.default_rng(2)
                     .standard_normal((1024, 1024)).astype(np.float32))
@@ -49,11 +46,11 @@ def run(quick: bool = False):
     _, t_r = timed(lambda: ref.group_quant_ref(g, 128)[0]
                    .block_until_ready(), repeat=3)
     rows.append(("group_quant", round(t_k * 1e6, 1), round(t_r * 1e6, 1),
-                 "-", round(g.size * 5 / 819e9 * 1e6, 3)))
+                 "-"))
 
     path = write_csv("kernels",
                      ["kernel", "pallas_interp_us", "jnp_ref_us",
-                      "numpy_us", "tpu_roofline_us"], rows)
+                      "numpy_us"], rows)
     return {"csv": path, "n_kernels": len(rows)}
 
 

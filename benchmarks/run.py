@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 
 from . import (bench_autotune, bench_density_sweep, bench_distributed,
@@ -48,6 +49,10 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.device import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     print("name,seconds,headline")
     for name, mod in BENCHES:
         if args.only and args.only not in name:

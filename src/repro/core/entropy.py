@@ -19,7 +19,10 @@ as ``sz.compress_lor_reg_batched``:
   * ``"pallas"``  — the window matrix is built by the
     ``repro.kernels.huffdec`` Pallas kernel and the decode walk runs as
     a jitted ``lax.scan`` on the accelerator; encode shares the batched
-    host path (bit packing is memory-bound scatter, not FLOPs);
+    host path (bit packing is memory-bound scatter, not FLOPs).  What
+    its guards send to the host is counted in
+    ``tacz_host_fallbacks_total``, what the device decoded in
+    ``tacz_device_items_total``;
   * ``"auto"``    — ``"pallas"`` when a TPU backend is attached,
     ``"batched"`` otherwise.
 
@@ -33,8 +36,11 @@ of the serial bit walk (see :func:`_decode_batched`).
 """
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
+from ..obs import metrics as obsm
 from . import huffman
 
 __all__ = [
@@ -60,10 +66,13 @@ ENGINE_NAMES = ("auto", "numpy", "batched", "pallas")
 _MIN_BATCH = 4
 _MAX_BATCH_MAXLEN = 57
 _MAX_WINDOW_ELEMS = 1 << 27
-# Pallas windows are int32 and the kernel pads the bit matrix into VMEM
-# tiles — much tighter budgets than the host path's.
+# Pallas windows are int32 (maxlen ≤ 30).  One device launch holds its bit
+# and window matrices in HBM, each padded to at most this many elements;
+# a larger batch runs as several launches, and only a payload too long
+# for a launch of its own goes to the host.
 _MAX_PALLAS_MAXLEN = 30
 _MAX_PALLAS_WINDOW_ELEMS = 1 << 24
+_HUFF_STAGE = "huffman_decode"
 
 
 # --------------------------------------------------------------------------
@@ -424,48 +433,137 @@ class BatchedEngine(EntropyEngine):
 class PallasEngine(BatchedEngine):
     """Decode through the ``repro.kernels.huffdec`` window kernel + jitted
     scan walk; encode shares the batched host scatter (bit packing is a
-    memory-bound byte shuffle — there is no FLOP win to move)."""
+    memory-bound byte shuffle — there is no FLOP win to move).
+
+    Payloads are sorted by length and cut into launches of bucketed
+    shapes (see ``huffdec.padded_shape``), so a process compiles a few
+    programs per octave of batch size, not one per batch.  ``device``
+    pins the launches to one jax device (default: JAX's default device).
+    """
 
     name = "pallas"
 
+    def __init__(self, device=None):
+        self.device = device
+
+    @staticmethod
+    def _host_reason(cb, triples) -> str | None:
+        """Why the whole batch goes to the host decoders, or None."""
+        if len(cb.symbols) <= 1:
+            return "degenerate_codebook"
+        if len(triples) < _MIN_BATCH:
+            return "tiny_batch"
+        if cb.max_length > _MAX_PALLAS_MAXLEN:
+            return "maxlen"
+        return None
+
     def decode_payloads(self, cb, payloads, n_codes=None):
         triples = _triples(payloads, n_codes)
-        if self._use_serial(cb, triples):
-            return [decode_stream(cb, buf, nbits, nc)
-                    for buf, nbits, nc in triples]
-        max_bits = max(min(nb, 8 * buf.size) for buf, nb, _ in triples)
-        if (cb.max_length > _MAX_PALLAS_MAXLEN
-                or len(triples) * (max_bits + cb.max_length + 1)
-                > _MAX_PALLAS_WINDOW_ELEMS):
-            return _decode_batched(cb, triples)
+        if not triples:
+            return []
+        reason = self._host_reason(cb, triples)
+        if reason is not None:
+            obsm.HOST_FALLBACKS.labels(_HUFF_STAGE, reason).inc(len(triples))
+            return BatchedEngine.decode_payloads(self, cb, triples)
+        nbits = np.array([min(nb, 8 * buf.size) for buf, nb, _ in triples],
+                         dtype=np.int64)
+        order = np.argsort(nbits, kind="stable")
+        out: list[np.ndarray | None] = [None] * len(triples)
+        launches, host = [], []
+        chunk: list[int] = []
+        for i in order.tolist():
+            if self._launch_elems(1, nbits[i]) > _MAX_PALLAS_WINDOW_ELEMS:
+                host.append(i)
+            elif self._launch_elems(len(chunk) + 1, nbits[i]) \
+                    > _MAX_PALLAS_WINDOW_ELEMS:
+                launches.append(chunk)
+                chunk = [i]
+            else:
+                chunk.append(i)
+        if chunk:
+            launches.append(chunk)
+        ok = True
+        if host:
+            obsm.HOST_FALLBACKS.labels(_HUFF_STAGE, "window_budget").inc(
+                len(host))
+            try:
+                for i, codes in zip(host, BatchedEngine.decode_payloads(
+                        self, cb, [triples[i] for i in host])):
+                    out[i] = codes
+            except ValueError:
+                ok = False
+        tables = _device_tables(cb) if launches else None
+        for idx in launches if ok else ():
+            ok = self._launch(cb, tables, triples, nbits, idx, out)
+            if not ok:
+                break
+        if not ok:
+            # error path: replay on the host so the raised error is the
+            # oracle's, for the lowest-index broken payload
+            BatchedEngine.decode_payloads(self, cb, triples)
+            raise RuntimeError("device Huffman decode reported a broken "
+                               "payload that the host decoders accept")
+        return out
+
+    @staticmethod
+    def _launch_elems(rows: int, max_nbits: int) -> int:
+        from repro.kernels import huffdec
+
+        r, w, _ = huffdec.padded_shape(rows, int(max_nbits) + 1)
+        return r * (w + huffdec.HALO)
+
+    def _launch(self, cb, tables, triples, nbits, idx, out) -> bool:
+        """Decode payloads ``idx`` (sorted by length) in one device
+        launch into ``out``; False when any of them is broken."""
+        import jax
+
+        from repro import device
         from repro.kernels import huffdec, ops
 
-        ls, uppers, maxlen = _decode_tables(cb)
-        bits, nbits_arr = _bit_matrix(triples, maxlen, pad=1)
-        width = int(nbits_arr.max(initial=0)) + 1
-        wm = ops.huffdec_windows(bits, maxlen=maxlen, width=width)
-        ncodes_arr = np.array([nc for _, _, nc in triples], dtype=np.int64)
-        steps = int(ncodes_arr.max(initial=0))
-        if steps == 0:
-            return [np.zeros(0, dtype=np.int64) for _ in triples]
-        sidx, err_kind = huffdec.decode_walk(
-            wm, nbits_arr.astype(np.int32), ncodes_arr.astype(np.int32),
-            uppers.astype(np.int32), ls.astype(np.int32),
-            cb.first_code.astype(np.int32), cb.first_index.astype(np.int32),
-            maxlen=maxlen, steps=steps)
-        _raise_payload_error(np.asarray(err_kind))
+        maxlen = cb.max_length
+        rows, width, col_tile = huffdec.padded_shape(
+            len(idx), int(nbits[idx[-1]]) + 1)
+        ncodes = np.zeros(rows, dtype=np.int32)
+        ncodes[:len(idx)] = [triples[i][2] for i in idx]
+        steps = huffdec.bucket(int(ncodes.max()), 8)
+        bits = np.zeros((rows, width + huffdec.HALO), dtype=np.uint8)
+        nb = np.zeros(rows, dtype=np.int32)
+        for r, i in enumerate(idx):
+            nb[r] = nbits[i]
+            if nb[r]:
+                bits[r, :nb[r]] = np.unpackbits(triples[i][0], count=nb[r])
+        with (jax.default_device(self.device) if self.device is not None
+              else contextlib.nullcontext()):
+            wm = ops.huffdec_windows(bits, maxlen=maxlen, col_tile=col_tile)
+            sidx, err = huffdec.decode_walk(wm, nb, ncodes, *tables,
+                                            maxlen=maxlen, steps=steps)
+        err = np.asarray(err)
+        obsm.DEVICE_ITEMS.labels(_HUFF_STAGE, device.device_label(wm)).inc(
+            len(idx))
+        if err.any():
+            return False
         # symbol values stay int64 on the host: the walk returns codebook
         # row indices, which always fit the device's int32 lanes
-        sidx = np.asarray(sidx)
-        out = []
-        for a, nc in enumerate(ncodes_arr):
-            nc = int(nc)
-            row = np.zeros(nc, dtype=np.int64)
-            if nc:
-                row[:] = cb.symbols[np.clip(sidx[a, :nc], 0,
-                                            len(cb.symbols) - 1)]
-            out.append(row)
-        return out
+        sym = cb.symbols[np.clip(np.asarray(sidx), 0, len(cb.symbols) - 1)]
+        for r, i in enumerate(idx):
+            out[i] = sym[r, :ncodes[r]].astype(np.int64)
+        return True
+
+
+def _device_tables(cb: huffman.Codebook) -> tuple[np.ndarray, ...]:
+    """``(uppers, n_lens, lens, first_code, first_index)`` as int32, padded
+    to fixed lengths so every codebook shares one walk program."""
+    ls, uppers, _ = _decode_tables(cb)
+    n = _MAX_PALLAS_MAXLEN + 2
+    up = np.full(n, np.iinfo(np.int32).max, dtype=np.int32)
+    up[:uppers.size] = uppers
+    lens = np.zeros(n, dtype=np.int32)
+    lens[:ls.size] = ls
+    fc = np.zeros(n, dtype=np.int32)
+    fc[:cb.first_code.size] = cb.first_code
+    fi = np.zeros(n, dtype=np.int32)
+    fi[:cb.first_index.size] = cb.first_index
+    return up, np.int32(ls.size), lens, fc, fi
 
 
 _ENGINES: dict[str, EntropyEngine] = {}
@@ -485,8 +583,8 @@ def get_engine(name: str | EntropyEngine = "auto") -> EntropyEngine:
         raise ValueError(f"unknown entropy engine {name!r} "
                          f"(expected one of {ENGINE_NAMES})")
     if name == "auto":
-        from .sz import _tpu_attached
-        name = "pallas" if _tpu_attached() else "batched"
+        from repro.device import tpu_attached
+        name = "pallas" if tpu_attached() else "batched"
     eng = _ENGINES.get(name)
     if eng is None:
         eng = _ENGINES.setdefault(

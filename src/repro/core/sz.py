@@ -626,17 +626,6 @@ def _code_cost_bits_rows(codes: np.ndarray) -> np.ndarray:
     return mag.reshape(mag.shape[0], -1).sum(axis=1) + 1.0
 
 
-def _tpu_attached() -> bool:
-    """True when JAX's default backend is a real TPU (ROADMAP open item:
-    the batched Lorenzo branch routes through the Pallas kernel there)."""
-    try:
-        import jax
-
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - jax always present in-repo
-        return False
-
-
 # One brick must fit in one VMEM tile (the kernel's zero-halo is per tile,
 # so tile == brick is the independence contract); this is the default tile's
 # footprint budget from repro.kernels.lorenzo3d.
@@ -657,17 +646,25 @@ def _lorenzo_codes_batched_pallas(x: np.ndarray, eb: float) -> np.ndarray | None
     the quantized magnitudes exceed the float32-exact integer range — past
     that the kernel's float32/int32 arithmetic would break the error
     bound rather than merely differ in last-ulp rounding.  The numpy host
-    path stays the bit-exact float64/int64 oracle.
+    path stays the bit-exact float64/int64 oracle.  Bricks go to
+    ``tacz_device_items_total`` or, with the guard that stopped them, to
+    ``tacz_host_fallbacks_total``.
     """
     shape = tuple(int(s) for s in x.shape[1:])
+    reason = None
     if int(np.prod(shape)) > _MAX_PALLAS_BRICK:
+        reason = "brick_size"
+    elif float(np.abs(x).max(initial=0.0)) / (2.0 * eb) >= _MAX_PALLAS_Q:
+        reason = "quant_range"
+    if reason is not None:
+        obsm.HOST_FALLBACKS.labels("lorenzo", reason).inc(x.shape[0])
         return None
-    if float(np.abs(x).max(initial=0.0)) / (2.0 * eb) >= _MAX_PALLAS_Q:
-        return None
+    from repro.device import device_label
     from repro.kernels import ops
 
     codes = ops.lorenzo3d_codes_batched(x.astype(np.float32), eb=float(eb),
                                         tile=shape)
+    obsm.DEVICE_ITEMS.labels("lorenzo", device_label(codes)).inc(x.shape[0])
     return np.asarray(codes).astype(np.int64)
 
 
@@ -711,7 +708,8 @@ def compress_lor_reg_batched(x: np.ndarray, eb: float, *, block: int = 6,
     with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("prequant"),
                     "prequant"):
         if engine == "auto":
-            engine = "pallas" if _tpu_attached() else "numpy"
+            from repro.device import tpu_attached
+            engine = "pallas" if tpu_attached() else "numpy"
         codes_lor = None
         if engine == "pallas":
             codes_lor = _lorenzo_codes_batched_pallas(x, eb)

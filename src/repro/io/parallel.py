@@ -260,15 +260,18 @@ class ParallelTACZWriter:
     :param sz_block: Lorenzo/regression block edge in cells.
     :param batched: run the batched SHE pipeline in workers.
     :param lorenzo_engine: ``"auto"``/``"numpy"``/``"pallas"`` —
-        resolved once on the producer so forked workers never probe
-        accelerator backends themselves.
+        resolved once on the producer so workers never probe accelerator
+        backends themselves; in ``"process"`` mode it resolves to the host
+        engine (one process per chip: children never ask for it).
     :param payload_codec: v2 lossless byte pass, as in ``TACZWriter``.
     :param entropy_engine: :mod:`repro.core.entropy` engine for the
         Huffman encode stage in workers (``"auto"``/``"numpy"``/
         ``"batched"``/``"pallas"``) — resolved once on the producer,
-        like ``lorenzo_engine``; output bytes are engine-independent.
+        like ``lorenzo_engine`` (host engines in ``"process"`` mode);
+        output bytes are engine-independent.
     :param queue_depth: per-part task queue bound (backpressure).
-    :raises ValueError: on bad ``parts``/``mode``/``payload_codec``.
+    :raises ValueError: on bad ``parts``/``mode``/``payload_codec``, or
+        a ``"pallas"`` engine with ``mode="process"``.
     :raises OSError: if the snapshot directory cannot be created.
     """
 
@@ -302,13 +305,13 @@ class ParallelTACZWriter:
                                                     range(self.parts)]
         self._finalized = False
         self._aborted = False
-        self._engine: str | None = None   # resolved lorenzo engine
-        self._entropy_eng: str | None = None   # resolved entropy engine
         os.makedirs(self.path, exist_ok=True)
 
         # resolve once on the producer, before any worker forks: the
-        # workers take the concrete engine name and never probe jax
-        ent_eng = self._resolve_entropy_engine()
+        # workers take the concrete engine names and never probe jax
+        self._engine = self._resolve("lorenzo_engine", "numpy")
+        self._entropy_eng = ent_eng = self._resolve("entropy_engine",
+                                                    "batched")
         depth = max(1, int(queue_depth))
         if mode == "process":
             # fork is the fast path; once XLA backends are live in this
@@ -399,35 +402,26 @@ class ParallelTACZWriter:
             raise ValueError("writer is closed")
         self._check_failures()
 
-    def _resolve_engine(self) -> str:
-        if self._engine is None:
-            eng = self._defaults["lorenzo_engine"]
-            if eng == "auto":
-                # resolve once on the producer so workers never probe the
-                # accelerator; don't *import* jax just to probe — pulling
-                # it in before a fork is exactly the hazard this avoids
-                if "jax" in sys.modules:
-                    from repro.core.sz import _tpu_attached
-                    eng = "pallas" if _tpu_attached() else "numpy"
-                else:
-                    eng = "numpy"
-            self._engine = eng
-        return self._engine
+    def _resolve(self, knob: str, host: str) -> str:
+        """Resolve one engine knob once, on the producer.
 
-    def _resolve_entropy_engine(self) -> str:
-        if self._entropy_eng is None:
-            eng = self._defaults["entropy_engine"]
-            if eng == "auto":
-                # same fork-safety rule as _resolve_engine: probe the
-                # accelerator only if jax is already imported; the
-                # batched numpy engine is the universal fallback
-                if "jax" in sys.modules:
-                    from repro.core.sz import _tpu_attached
-                    eng = "pallas" if _tpu_attached() else "batched"
-                else:
-                    eng = "batched"
-            self._entropy_eng = eng
-        return self._entropy_eng
+        Process workers run on the host: a device engine there would
+        have every child ask for the chip this process may hold, so
+        ``"auto"`` resolves to the host engine and ``"pallas"`` is
+        refused.  Thread workers share this process's device, so
+        ``"auto"`` probes the backend.
+        """
+        eng = self._defaults[knob]
+        if self.mode == "process":
+            if eng == "pallas":
+                raise ValueError(
+                    f"{knob}='pallas' needs mode='thread': process workers "
+                    f"never use the accelerator")
+            return host if eng == "auto" else eng
+        if eng == "auto":
+            from repro.device import tpu_attached
+            eng = "pallas" if tpu_attached() else host
+        return eng
 
     def _owners(self, li: int, keys: list[tuple[int, int]],
                 ) -> list[list[int]]:
@@ -493,8 +487,8 @@ class ParallelTACZWriter:
                     orig_shape=tuple(data.shape), grid_shape=grid_shape,
                     density=float(density), n_values=int(mask.sum()),
                     batched=bool(d["batched"]),
-                    lorenzo_engine=self._resolve_engine(),
-                    entropy_engine=self._resolve_entropy_engine(),
+                    lorenzo_engine=self._engine,
+                    entropy_engine=self._entropy_eng,
                     mask_packed=(None if mask.all()
                                  else np.packbits(mask.ravel()).tobytes()))
         if strategy == "gsp":

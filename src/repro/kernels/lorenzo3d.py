@@ -13,7 +13,9 @@ brick shape the caller compresses; the default (8, 128, 128) fits
 8·128·128·4 B · 3 buffers ≈ 1.6 MB of VMEM.
 
 The inverse kernel reconstructs ``x̂ = 2eb · cumsum³(codes)`` — exact in
-integers, so the error bound is the prequant bound.
+integers, so the error bound is the prequant bound.  Mosaic has no
+``cumsum`` lowering, so each inclusive scan is a log-step shift-and-add
+(the same zero-fill shifts the forward kernel subtracts).
 """
 from __future__ import annotations
 
@@ -28,6 +30,26 @@ __all__ = ["lorenzo3d_codes_kernel", "lorenzo3d_recon_kernel",
            "lorenzo3d_codes_batched", "lorenzo3d_recon_batched"]
 
 
+def _shift(c, ax: int, s: int = 1):
+    """``c`` shifted by ``s`` toward higher indices along ``ax``, zero-filled
+    at the low face."""
+    n = c.shape[ax]
+    return jnp.pad(c, [(s, 0) if a == ax else (0, 0)
+                       for a in range(c.ndim)])[
+        tuple(slice(0, n) if a == ax else slice(None)
+              for a in range(c.ndim))]
+
+
+def _inclusive_scan(q, ax: int):
+    """Integer inclusive prefix sum along ``ax`` by log-step shift-and-add
+    (Hillis–Steele): exact in int32, VPU-only."""
+    s = 1
+    while s < q.shape[ax]:
+        q = q + _shift(q, ax, s)
+        s *= 2
+    return q
+
+
 def lorenzo3d_codes_kernel(x_ref, codes_ref, *, inv_2eb: float):
     """One VMEM tile: prequant then zero-halo 3D Lorenzo delta."""
     x = x_ref[...]
@@ -36,10 +58,7 @@ def lorenzo3d_codes_kernel(x_ref, codes_ref, *, inv_2eb: float):
     # implemented as shift-and-subtract (VPU-only, no gathers)
     c = q
     for ax in range(3):
-        shifted = jnp.pad(c, [(1, 0) if a == ax else (0, 0)
-                              for a in range(3)])[
-            tuple(slice(0, -1) if a == ax else slice(None) for a in range(3))]
-        c = c - shifted
+        c = c - _shift(c, ax)
     codes_ref[...] = c
 
 
@@ -47,7 +66,7 @@ def lorenzo3d_recon_kernel(codes_ref, x_ref, *, two_eb: float):
     """Inverse tile: integer 3D inclusive scan, then dequantize."""
     q = codes_ref[...].astype(jnp.int32)
     for ax in range(3):
-        q = jnp.cumsum(q, axis=ax)
+        q = _inclusive_scan(q, ax)
     x_ref[...] = q.astype(jnp.float32) * two_eb
 
 
@@ -110,17 +129,14 @@ def lorenzo3d_codes_batched_kernel(x_ref, codes_ref, *, inv_2eb: float):
     x = x_ref[...]
     c = jnp.rint(x * inv_2eb).astype(jnp.int32)
     for ax in (1, 2, 3):
-        shifted = jnp.pad(c, [(1, 0) if a == ax else (0, 0)
-                              for a in range(4)])[
-            tuple(slice(0, -1) if a == ax else slice(None) for a in range(4))]
-        c = c - shifted
+        c = c - _shift(c, ax)
     codes_ref[...] = c
 
 
 def lorenzo3d_recon_batched_kernel(codes_ref, x_ref, *, two_eb: float):
     q = codes_ref[...].astype(jnp.int32)
     for ax in (1, 2, 3):
-        q = jnp.cumsum(q, axis=ax)
+        q = _inclusive_scan(q, ax)
     x_ref[...] = q.astype(jnp.float32) * two_eb
 
 

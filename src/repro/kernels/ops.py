@@ -59,11 +59,13 @@ def hist(codes, *, n_bins: int = 1024, chunk: int = 8192,
         interpret=default_interpret() if interpret is None else interpret)
 
 
-def huffdec_windows(bits, *, maxlen: int, width: int, row_tile: int = 8,
+def huffdec_windows(bits, *, maxlen: int, col_tile: int,
+                    row_tile: int = _huffdec.ROW_TILE,
                     interpret: bool | None = None):
-    """Stacked maxlen-bit windows for batched canonical-Huffman decode."""
+    """Stacked maxlen-bit windows for batched canonical-Huffman decode
+    (``bits`` shaped by ``huffdec.padded_shape``)."""
     return _huffdec.huffdec_windows(
-        bits, maxlen=maxlen, width=width, row_tile=row_tile,
+        bits, maxlen=maxlen, col_tile=col_tile, row_tile=row_tile,
         interpret=default_interpret() if interpret is None else interpret)
 
 

@@ -23,9 +23,15 @@ HARDWARE = {
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_smoke_mesh():
     """1-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto(2))
+
+
+def _auto(n: int) -> tuple:
+    # sharding rules here rely on Auto propagation (P.UNCONSTRAINED specs,
+    # vocab-sharded gathers); jax.make_mesh defaults to Explicit axes
+    return (jax.sharding.AxisType.Auto,) * n

@@ -27,6 +27,7 @@ from .trace import trace as _trace
 __all__ = [
     "REGISTRY", "set_enabled", "is_enabled", "timed",
     "COMPRESS_STAGE_SECONDS", "COMPRESS_LEVEL_SECONDS",
+    "DEVICE_ITEMS", "HOST_FALLBACKS",
     "WRITER_LEVEL_SECONDS", "WRITER_BYTES", "WRITER_LEVELS",
     "PLANNER_SUBBLOCKS", "PLANNER_DECODE_SECONDS", "PLANNER_DECODED_BYTES",
     "ENTROPY_DECODE_SECONDS",
@@ -103,6 +104,24 @@ COMPRESS_LEVEL_SECONDS = REGISTRY.histogram(
     "End-to-end compress_level wall time, labeled by the resolved "
     "strategy (gsp | opst | akdtree | nast).",
     labels=("strategy",))
+
+# ------------------------- device vs host routing --------------------------
+# The device engines (Pallas Lorenzo codes, Pallas Huffman decode) keep
+# correctness guards that send some work to the host oracles; both sides
+# are counted so a run can show how much of a stage the device did.
+
+DEVICE_ITEMS = REGISTRY.counter(
+    "tacz_device_items_total",
+    "Work items a device kernel processed (stage: lorenzo = bricks, "
+    "huffman_decode = payloads), by device (platform:id).",
+    labels=("stage", "device"))
+
+HOST_FALLBACKS = REGISTRY.counter(
+    "tacz_host_fallbacks_total",
+    "Work items a device engine sent to a host path instead, by stage and "
+    "guard (reason: brick_size | quant_range | degenerate_codebook | "
+    "tiny_batch | maxlen | window_budget).",
+    labels=("stage", "reason"))
 
 # ------------------------------ writers ----------------------------------
 

@@ -11,8 +11,9 @@ file into a queryable region service in three layers:
     keyed on (level, sub-block index), with hit/miss/eviction counters.
     Overlapping queries pay each brick's entropy decode once.
   * :class:`DecodePlanner` — maps a batch of ROI boxes to the minimal set
-    of *uncached* sub-blocks, groups them by (level, shape, branch), and
-    reconstructs each group through one vectorized
+    of *uncached* sub-blocks, entropy-decodes each level's in one batched
+    launch, and reconstructs each (level, shape, branch) group through
+    one vectorized
     ``sz.decode_codes_batched`` launch instead of PR 2's per-brick serial
     ``decode_codes`` walk.
   * :class:`RegionServer` — ``get_region(level, box)`` /
@@ -40,7 +41,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from repro import obs
-from repro.core import sz
+from repro.core import entropy, sz
 from repro.io import format as fmt
 from repro.io import frontier as frt
 from repro.io.reader import (WHOLE_LEVEL, Box, ROILevel, TACZReader,
@@ -359,7 +360,7 @@ class DecodePlanner:
         with obsm.timed(obsm.PLANNER_DECODE_SECONDS.labels(), "decode"):
             # gsp/global levels: single global payload each — decode
             # serially
-            groups: dict[tuple[int, tuple[int, ...], int], list[int]] = {}
+            by_level: dict[int, list[int]] = {}
             for li, sbi in missing:
                 if sbi == WHOLE_LEVEL:
                     full = rd.read_level(li)
@@ -367,30 +368,47 @@ class DecodePlanner:
                     out[(li, sbi)] = full
                     decoded_bytes += full.nbytes
                 else:
-                    sb = rd.levels[li].subblocks[sbi]
-                    groups.setdefault(
-                        (li, rd.subblock_shape(li, sbi), sb.branch),
-                        []).append(sbi)
-            # SHE sub-blocks: one batched EntropyEngine launch per group's
-            # payloads, then one vectorized reconstruction per (level,
-            # shape, branch) group — no per-payload serial bit-walk
-            # anywhere
-            for (li, shape, branch), sbis in groups.items():
+                    by_level.setdefault(li, []).append(sbi)
+            # SHE sub-blocks: one batched EntropyEngine launch per level
+            # (its payloads share one codebook), then one vectorized
+            # reconstruction per (shape, branch) group — no per-payload
+            # serial bit-walk anywhere
+            for li, sbis in by_level.items():
                 e = rd.levels[li]
-                decoded = rd.decode_subblocks(li, sbis)
-                codes = np.stack([c for c, _ in decoded])
-                betas = (np.stack([b for _, b in decoded])
-                         if branch == fmt.BRANCH_REG else None)
-                recon = sz.decode_codes_batched(
-                    codes, shape, e.eb, branch=fmt.BRANCH_NAMES[branch],
-                    block=e.sz_block, betas=betas)
-                for sbi, brick in zip(sbis, recon):
-                    brick = brick.copy()   # detach from the stacked batch
-                    cache.put((gen, li, sbi), brick)
-                    out[(li, sbi)] = brick
-                    decoded_bytes += brick.nbytes
+                decoded = dict(zip(sbis, rd.decode_subblocks(li, sbis)))
+                groups: dict[tuple[tuple[int, ...], int], list[int]] = {}
+                for sbi in sbis:
+                    groups.setdefault((rd.subblock_shape(li, sbi),
+                                       e.subblocks[sbi].branch),
+                                      []).append(sbi)
+                for (shape, branch), g in groups.items():
+                    codes = np.stack([decoded[sbi][0] for sbi in g])
+                    betas = (np.stack([decoded[sbi][1] for sbi in g])
+                             if branch == fmt.BRANCH_REG else None)
+                    recon = sz.decode_codes_batched(
+                        codes, shape, e.eb, branch=fmt.BRANCH_NAMES[branch],
+                        block=e.sz_block, betas=betas)
+                    for sbi, brick in zip(g, recon):
+                        brick = brick.copy()   # detach from the stacked batch
+                        cache.put((gen, li, sbi), brick)
+                        out[(li, sbi)] = brick
+                        decoded_bytes += brick.nbytes
         obsm.PLANNER_DECODED_BYTES.inc(decoded_bytes)
         return out
+
+
+def _shard_engine(name, shard_map, shard_id):
+    """The engine a shard server decodes with: a device engine is pinned
+    to the shard's own local device (see :class:`RegionServer`)."""
+    eng = entropy.get_engine(name)
+    shards = list(getattr(shard_map, "shards", ()))
+    if not isinstance(eng, entropy.PallasEngine) or shard_id not in shards:
+        return name
+    import jax
+
+    devs = jax.local_devices()
+    return entropy.PallasEngine(
+        device=devs[shards.index(shard_id) % len(devs)])
 
 
 class RegionServer:
@@ -432,7 +450,10 @@ class RegionServer:
         decodes Huffman payloads with on cache misses (``"auto"``/
         ``"numpy"``/``"batched"``/``"pallas"``).  Engines are
         bit-identical, so served crops never depend on the choice;
-        hot-swapped readers keep the same engine.
+        hot-swapped readers keep the same engine.  A shard server whose
+        engine is ``"pallas"`` decodes on its own device — shard ``i`` of
+        the map's sorted shard list on local device ``i mod n`` — so
+        shard servers in one process spread over the host's chips.
     :raises ValueError: if only one of ``shard_map``/``shard_id`` is given,
         or the file fails TACZ validation.
     :raises OSError: if the file cannot be opened.
@@ -445,6 +466,9 @@ class RegionServer:
         if (shard_map is None) != (shard_id is None):
             raise ValueError("shard_map and shard_id go together")
         self.path = str(path)
+        if shard_map is not None:
+            entropy_engine = _shard_engine(entropy_engine, shard_map,
+                                           shard_id)
         self.entropy_engine = entropy_engine
         self.auto_reload = bool(auto_reload)
         self.shard_map = shard_map

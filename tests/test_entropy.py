@@ -77,6 +77,7 @@ def test_engine_registry():
     (1, 3, 100),       # still below _MIN_BATCH — serial fallback path
     (2, 12, 200),      # batched path, mixed sizes incl. empty payloads
     (3, 40, 64),       # many small payloads
+    (4, 6, 3000),      # payloads spanning several device bit-axis tiles
 ])
 def test_engine_matches_oracle(engine, seed, n_payloads, max_codes):
     cb, codes_list = _batch(seed, n_payloads, max_codes)
@@ -330,6 +331,41 @@ def test_multipart_decode_subblocks_across_parts(tmp_path):
                     np.testing.assert_array_equal(dec[pos][1], b)
             np.testing.assert_array_equal(rd.read_level(li),
                                           ref.read_level(li))
+
+
+# ------------------------- device/host accounting ---------------------------
+
+
+def _counter(family, *labels):
+    child = family.children().get(tuple(labels))
+    return 0.0 if child is None else child.value
+
+
+def test_window_budget_fallback_counted(monkeypatch):
+    """A payload too long for a device launch of its own is decoded on
+    the host and counted under ``window_budget``; the rest still run on
+    the device in several launches, and every output matches the oracle."""
+    from repro.obs import metrics as obsm
+    rng = np.random.default_rng(31)
+    cb = huffman.build_codebook(rng.integers(-20, 21, size=5000))
+    sizes = [40, 60, 80, 100, 120, 2000]
+    codes_list = [rng.integers(-20, 21, size=n) for n in sizes]
+    enc = entropy.get_engine("numpy").encode_payloads(cb, codes_list)
+    payloads = [(blob, nbits, c.size)
+                for (blob, nbits), c in zip(enc, codes_list)]
+    eng = entropy.PallasEngine()
+    longest = max(nbits for _, nbits, _ in payloads)
+    # the longest payload alone overflows; two short ones fit per launch
+    budget = eng._launch_elems(2, sorted(p[1] for p in payloads)[-2])
+    assert eng._launch_elems(1, longest) > budget
+    monkeypatch.setattr(entropy, "_MAX_PALLAS_WINDOW_ELEMS", budget)
+    host = (obsm.HOST_FALLBACKS, "huffman_decode", "window_budget")
+    dev = (obsm.DEVICE_ITEMS, "huffman_decode", "cpu:0")
+    h0, d0 = _counter(*host), _counter(*dev)
+    for out, ref in zip(eng.decode_payloads(cb, payloads), codes_list):
+        np.testing.assert_array_equal(out, ref)
+    assert _counter(*host) - h0 == 1
+    assert _counter(*dev) - d0 == len(sizes) - 1
 
 
 # --------------------------- hypothesis sweeps ------------------------------

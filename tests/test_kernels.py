@@ -80,6 +80,23 @@ def test_lorenzo3d_batched_matches_per_brick(shape, tile):
         <= eb + float(jnp.abs(x).max()) * 2.0 ** -22
 
 
+@pytest.mark.parametrize("rows,nbits,maxlen", [
+    (3, 100, 5),            # one narrow tile
+    (11, 5000, 17),         # several bit-axis tiles: windows cross the halo
+    (8, 2047, 30),          # the longest codes, ending at a tile edge
+])
+def test_huffdec_windows_vs_numpy(rows, nbits, maxlen):
+    from repro.core import entropy
+    from repro.kernels import huffdec
+    rng = np.random.default_rng(rows * nbits)
+    r, w, col_tile = huffdec.padded_shape(rows, nbits)
+    bits = np.zeros((r, w + huffdec.HALO), np.uint8)
+    bits[:rows, :nbits] = rng.integers(0, 2, size=(rows, nbits))
+    wm = ops.huffdec_windows(bits, maxlen=maxlen, col_tile=col_tile)
+    np.testing.assert_array_equal(
+        np.asarray(wm), entropy._window_matrix(bits, maxlen, w))
+
+
 @pytest.mark.parametrize("n,n_bins,chunk", [
     (1000, 64, 256), (8192, 1024, 8192), (5000, 128, 1024), (10, 16, 8)])
 def test_hist_vs_ref(n, n_bins, chunk):

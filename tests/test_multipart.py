@@ -254,6 +254,49 @@ def test_manifest_crc_and_part_binding(make_amr_snapshot):
 # --------------------------- crash consistency ------------------------------
 
 
+def test_process_workers_never_get_a_device_engine(tmp_path, monkeypatch,
+                                                   make_amr_snapshot):
+    """One process per chip: even when the backend probe reports a TPU,
+    process-mode workers are handed host engines (and an explicit device
+    engine is refused), while thread workers follow the probe."""
+    import repro.device
+
+    monkeypatch.setattr(repro.device, "tpu_attached", lambda: True)
+    snap = make_amr_snapshot(densities=[0.35, 0.65], seed=5)
+    path = os.path.join(str(tmp_path), "proc.taczd")
+    heads = []
+    with ParallelTACZWriter(path, parts=2, mode="process",
+                            eb=snap.eb) as w:
+        # the part writers were started with this engine
+        assert w._entropy_eng == "batched"
+        dispatch = w._dispatch
+
+        def spy(pi, task, check=True):
+            if isinstance(task, dict) and "head" in task:
+                heads.append(task["head"])
+            return dispatch(pi, task, check)
+
+        w._dispatch = spy
+        for lvl in snap.ds.levels:
+            w.add_level(lvl.data, lvl.mask, ratio=lvl.ratio)
+    assert heads and all(h["lorenzo_engine"] == "numpy"
+                         and h["entropy_engine"] == "batched"
+                         for h in heads)
+    with MultiPartReader(path) as rd:
+        for lr, rec in zip(snap.res.levels, rd.read()):
+            assert rec.shape == lr.recon.shape
+    for knob in ("lorenzo_engine", "entropy_engine"):
+        with pytest.raises(ValueError, match="process workers"):
+            ParallelTACZWriter(os.path.join(str(tmp_path), knob),
+                               mode="process", **{knob: "pallas"})
+    w = ParallelTACZWriter(os.path.join(str(tmp_path), "thr"), parts=1,
+                           mode="thread", eb=snap.eb)
+    try:
+        assert (w._engine, w._entropy_eng) == ("pallas", "pallas")
+    finally:
+        w.abort()
+
+
 def test_killed_part_worker_never_publishes(tmp_path, make_amr_snapshot):
     """Kill one part worker mid-republish: close() must fail, the new
     manifest must not appear, the victim's tmp litter is detected — and

@@ -437,3 +437,32 @@ if HAVE_HYPOTHESIS:
         for k in keys:
             before, after = m.owner(k), grown.owner(k)
             assert after == before or after == "new"
+
+
+def test_shard_servers_decode_on_their_own_device(snapshot):
+    """A shard server with a device engine is pinned to local device
+    ``i mod n`` (i = its place in the sorted shard list) and serves the
+    same crops as an unsharded host-engine server; a host engine is left
+    as named."""
+    import jax
+
+    from repro.core import entropy
+    path, _ = snapshot
+    devs = jax.local_devices()
+    smap = ShardMap(["b", "a", "c"])
+    box = BOXES[3]
+    with RegionServer(path, entropy_engine="numpy") as ref:
+        want = ref.get_roi(box)
+    got = [np.zeros_like(r.data) for r in want]
+    for i, sid in enumerate(smap.shards):
+        with RegionServer(path, shard_map=smap, shard_id=sid,
+                          entropy_engine="pallas") as srv:
+            assert isinstance(srv.entropy_engine, entropy.PallasEngine)
+            assert srv.entropy_engine.device == devs[i % len(devs)]
+            for acc, roi in zip(got, srv.get_roi(box)):
+                acc += roi.data      # foreign cells come back as zeros
+    for g, r in zip(got, want):
+        np.testing.assert_array_equal(g, r.data)
+    with RegionServer(path, shard_map=smap, shard_id="a",
+                      entropy_engine="batched") as srv:
+        assert srv.entropy_engine == "batched"

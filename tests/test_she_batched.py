@@ -9,7 +9,7 @@ the optional dep is installed (CI always has it).
 import numpy as np
 import pytest
 
-from repro.core import amr, she
+from repro.core import amr, she, sz
 from repro.core.akdtree import akdtree_partition
 from repro.core.blocks import extract_subblock, make_block_grid
 from repro.core.opst import opst_partition
@@ -253,3 +253,29 @@ if HAVE_HYPOTHESIS:
             np.testing.assert_array_equal(batched[idx].codes, ref.codes)
             np.testing.assert_array_equal(batched[idx].recon, ref.recon)
             assert batched[idx].extras["branch"] == ref.extras["branch"]
+
+
+def test_oversize_brick_fallback_counted():
+    """A brick over the VMEM tile budget is compressed by the numpy oracle
+    and counted under ``brick_size``; a brick within it is counted as
+    device work — both bound-correct."""
+    from repro.obs import metrics as obsm
+
+    def count(family, *labels):
+        child = family.children().get(labels)
+        return 0.0 if child is None else child.value
+
+    eb = 1e-2
+    rng = np.random.default_rng(3)
+    big = rng.normal(size=(1, 8, 128, 129)).astype(np.float32)
+    small = rng.normal(size=(3, 8, 8, 8)).astype(np.float32)
+    assert np.prod(big.shape[1:]) > sz._MAX_PALLAS_BRICK
+    host = (obsm.HOST_FALLBACKS, "lorenzo", "brick_size")
+    dev = (obsm.DEVICE_ITEMS, "lorenzo", "cpu:0")
+    h0, d0 = count(*host), count(*dev)
+    for x in (big, small):
+        for r, brick in zip(sz.compress_lor_reg_batched(x, eb,
+                                                        engine="pallas"), x):
+            assert np.abs(r.recon - brick).max() <= eb * (1 + 1e-5)
+    assert count(*host) - h0 == 1
+    assert count(*dev) - d0 == 3
