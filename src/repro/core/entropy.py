@@ -75,6 +75,14 @@ _MAX_PALLAS_WINDOW_ELEMS = 1 << 24
 _HUFF_STAGE = "huffman_decode"
 
 
+def _decode_stage(stage: str):
+    """Time one stage of a payload decode (``pack``, ``device``,
+    ``unpack`` or ``host``) into ``tacz_entropy_decode_stage_seconds``
+    and the profiler's ``layer.decode.<stage>``."""
+    return obsm.timed(obsm.ENTROPY_DECODE_STAGE_SECONDS.labels(stage),
+                      layer="layer.decode." + stage)
+
+
 # --------------------------------------------------------------------------
 # serial primitives — the bit-exact oracle (moved from repro.core.huffman)
 # --------------------------------------------------------------------------
@@ -347,8 +355,9 @@ class NumpyEngine(EntropyEngine):
         return out
 
     def decode_payloads(self, cb, payloads, n_codes=None):
-        return [decode_stream(cb, buf, nbits, nc)
-                for buf, nbits, nc in _triples(payloads, n_codes)]
+        with _decode_stage("host"):
+            return [decode_stream(cb, buf, nbits, nc)
+                    for buf, nbits, nc in _triples(payloads, n_codes)]
 
 
 class BatchedEngine(EntropyEngine):
@@ -402,11 +411,12 @@ class BatchedEngine(EntropyEngine):
         return out
 
     def decode_payloads(self, cb, payloads, n_codes=None):
-        triples = self._serial_or_none(cb, _triples(payloads, n_codes))
-        if isinstance(triples, list) and triples and \
-                isinstance(triples[0], np.ndarray):
-            return triples
-        return _decode_batched(cb, triples)
+        with _decode_stage("host"):
+            triples = self._serial_or_none(cb, _triples(payloads, n_codes))
+            if isinstance(triples, list) and triples and \
+                    isinstance(triples[0], np.ndarray):
+                return triples
+            return _decode_batched(cb, triples)
 
     def _serial_or_none(self, cb, triples):
         """Serial fallback (identical results) for the cases batching
@@ -465,23 +475,25 @@ class PallasEngine(BatchedEngine):
         if reason is not None:
             obsm.HOST_FALLBACKS.labels(_HUFF_STAGE, reason).inc(len(triples))
             return BatchedEngine.decode_payloads(self, cb, triples)
-        nbits = np.array([min(nb, 8 * buf.size) for buf, nb, _ in triples],
-                         dtype=np.int64)
-        order = np.argsort(nbits, kind="stable")
-        out: list[np.ndarray | None] = [None] * len(triples)
-        launches, host = [], []
-        chunk: list[int] = []
-        for i in order.tolist():
-            if self._launch_elems(1, nbits[i]) > _MAX_PALLAS_WINDOW_ELEMS:
-                host.append(i)
-            elif self._launch_elems(len(chunk) + 1, nbits[i]) \
-                    > _MAX_PALLAS_WINDOW_ELEMS:
+        with _decode_stage("pack"):
+            nbits = np.array([min(nb, 8 * buf.size)
+                              for buf, nb, _ in triples], dtype=np.int64)
+            order = np.argsort(nbits, kind="stable")
+            out: list[np.ndarray | None] = [None] * len(triples)
+            launches, host = [], []
+            chunk: list[int] = []
+            for i in order.tolist():
+                if self._launch_elems(1, nbits[i]) > _MAX_PALLAS_WINDOW_ELEMS:
+                    host.append(i)
+                elif self._launch_elems(len(chunk) + 1, nbits[i]) \
+                        > _MAX_PALLAS_WINDOW_ELEMS:
+                    launches.append(chunk)
+                    chunk = [i]
+                else:
+                    chunk.append(i)
+            if chunk:
                 launches.append(chunk)
-                chunk = [i]
-            else:
-                chunk.append(i)
-        if chunk:
-            launches.append(chunk)
+            tables = _device_tables(cb) if launches else None
         ok = True
         if host:
             obsm.HOST_FALLBACKS.labels(_HUFF_STAGE, "window_budget").inc(
@@ -492,7 +504,6 @@ class PallasEngine(BatchedEngine):
                     out[i] = codes
             except ValueError:
                 ok = False
-        tables = _device_tables(cb) if launches else None
         for idx in launches if ok else ():
             ok = self._launch(cb, tables, triples, nbits, idx, out)
             if not ok:
@@ -520,33 +531,40 @@ class PallasEngine(BatchedEngine):
         from repro import device
         from repro.kernels import huffdec, ops
 
-        maxlen = cb.max_length
-        rows, width, col_tile = huffdec.padded_shape(
-            len(idx), int(nbits[idx[-1]]) + 1)
-        ncodes = np.zeros(rows, dtype=np.int32)
-        ncodes[:len(idx)] = [triples[i][2] for i in idx]
-        steps = huffdec.bucket(int(ncodes.max()), 8)
-        bits = np.zeros((rows, width + huffdec.HALO), dtype=np.uint8)
-        nb = np.zeros(rows, dtype=np.int32)
-        for r, i in enumerate(idx):
-            nb[r] = nbits[i]
-            if nb[r]:
-                bits[r, :nb[r]] = np.unpackbits(triples[i][0], count=nb[r])
-        with (jax.default_device(self.device) if self.device is not None
-              else contextlib.nullcontext()):
-            wm = ops.huffdec_windows(bits, maxlen=maxlen, col_tile=col_tile)
-            sidx, err = huffdec.decode_walk(wm, nb, ncodes, *tables,
-                                            maxlen=maxlen, steps=steps)
-        err = np.asarray(err)
-        obsm.DEVICE_ITEMS.labels(_HUFF_STAGE, device.device_label(wm)).inc(
-            len(idx))
+        with _decode_stage("pack"):
+            maxlen = cb.max_length
+            rows, width, col_tile = huffdec.padded_shape(
+                len(idx), int(nbits[idx[-1]]) + 1)
+            ncodes = np.zeros(rows, dtype=np.int32)
+            ncodes[:len(idx)] = [triples[i][2] for i in idx]
+            steps = huffdec.bucket(int(ncodes.max()), 8)
+            bits = np.zeros((rows, width + huffdec.HALO), dtype=np.uint8)
+            nb = np.zeros(rows, dtype=np.int32)
+            for r, i in enumerate(idx):
+                nb[r] = nbits[i]
+                if nb[r]:
+                    bits[r, :nb[r]] = np.unpackbits(triples[i][0],
+                                                    count=nb[r])
+        with _decode_stage("device"):
+            with (jax.default_device(self.device) if self.device is not None
+                  else contextlib.nullcontext()):
+                wm = ops.huffdec_windows(bits, maxlen=maxlen,
+                                         col_tile=col_tile)
+                sidx, err = huffdec.decode_walk(wm, nb, ncodes, *tables,
+                                                maxlen=maxlen, steps=steps)
+            err = np.asarray(err)
+            sidx = np.asarray(sidx)
+            obsm.DEVICE_ITEMS.labels(_HUFF_STAGE,
+                                     device.device_label(wm)).inc(len(idx))
         if err.any():
             return False
-        # symbol values stay int64 on the host: the walk returns codebook
-        # row indices, which always fit the device's int32 lanes
-        sym = cb.symbols[np.clip(np.asarray(sidx), 0, len(cb.symbols) - 1)]
-        for r, i in enumerate(idx):
-            out[i] = sym[r, :ncodes[r]].astype(np.int64)
+        with _decode_stage("unpack"):
+            # symbol values stay int64 on the host: the walk returns
+            # codebook row indices, which always fit the device's int32
+            # lanes
+            sym = cb.symbols[np.clip(sidx, 0, len(cb.symbols) - 1)]
+            for r, i in enumerate(idx):
+                out[i] = sym[r, :ncodes[r]].astype(np.int64)
         return True
 
 

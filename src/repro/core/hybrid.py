@@ -196,9 +196,12 @@ def compress_level(data: np.ndarray, mask: np.ndarray, *, eb: float,
                    lorenzo_engine: str = "auto",
                    entropy_engine: str = "auto") -> LevelResult:
     """One level end to end; records per-strategy wall time into
-    ``tacz_compress_level_seconds`` (stage timings — prequant,
-    branch_score, entropy — are recorded inside sz/she)."""
-    with obs.trace("compress_level"):
+    ``tacz_compress_level_seconds``.  Stage timings
+    (``tacz_compress_stage_seconds``) are recorded where each stage runs:
+    partition, the brick gather and the scatter back into the level grid
+    here; brick stacking and entropy in she; prequant, branch_score and
+    the branch reconstruction in sz."""
+    with obs.trace("compress_level", "layer.compress.level"):
         t0 = time.perf_counter()
         res = _compress_level(
             data, mask, eb=eb, unit=unit, algorithm=algorithm, she=she,
@@ -217,9 +220,12 @@ def _compress_level(data: np.ndarray, mask: np.ndarray, *, eb: float,
                     ratio: int = 1, keep_artifacts: bool = True,
                     lorenzo_engine: str = "auto",
                     entropy_engine: str = "auto") -> LevelResult:
-    grid, strategy, density, subblocks = partition_level(
-        data, mask, unit=unit, algorithm=algorithm, she=she,
-        strategy=strategy)
+    with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("partition"),
+                    "partition", "layer.compress.partition"):
+        grid, strategy, density, subblocks = partition_level(
+            data, mask, unit=unit, algorithm=algorithm, she=she,
+            strategy=strategy)
+        sb_meta = sum(sb.meta_bits() for sb in subblocks)
 
     orig_shape = data.shape
 
@@ -243,29 +249,33 @@ def _compress_level(data: np.ndarray, mask: np.ndarray, *, eb: float,
                            density=density, eb=eb, ratio=ratio,
                            artifacts=art)
 
-    sb_meta = sum(sb.meta_bits() for sb in subblocks)
     u = grid.unit
 
     if she and algorithm == "lor_reg":
-        bricks = [extract_subblock(grid, sb) for sb in subblocks]
+        with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("gather"),
+                        "gather", "layer.compress.gather"):
+            bricks = [extract_subblock(grid, sb) for sb in subblocks]
         enc = she_encode(bricks, eb, block=sz_block, shared=True,
                          batched=batched, lorenzo_engine=lorenzo_engine,
                          entropy_engine=entropy_engine)
-        recon = np.zeros(grid.data.shape, dtype=np.float32)
-        for sb, r in zip(subblocks, enc.results):
-            ox, oy, oz = sb.cell_origin(u)
-            sx, sy, sz = sb.cell_size(u)
-            recon[ox:ox + sx, oy:oy + sy, oz:oz + sz] = r.recon
-        recon = recon[tuple(slice(0, s) for s in orig_shape)]
-        recon = np.where(mask, recon, 0.0).astype(np.float32)
-        art = None
-        if keep_artifacts:
-            art = LevelArtifacts(mask=np.asarray(mask, dtype=bool),
-                                 orig_shape=tuple(orig_shape),
-                                 grid_shape=tuple(grid.data.shape),
-                                 unit=grid.unit, sz_block=sz_block,
-                                 subblocks=subblocks, results=enc.results,
-                                 codebook=enc.codebook)
+        with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("recon"),
+                        "recon", "layer.compress.recon"):
+            recon = np.zeros(grid.data.shape, dtype=np.float32)
+            for sb, r in zip(subblocks, enc.results):
+                ox, oy, oz = sb.cell_origin(u)
+                sx, sy, sz = sb.cell_size(u)
+                recon[ox:ox + sx, oy:oy + sy, oz:oz + sz] = r.recon
+            recon = recon[tuple(slice(0, s) for s in orig_shape)]
+            recon = np.where(mask, recon, 0.0).astype(np.float32)
+            art = None
+            if keep_artifacts:
+                art = LevelArtifacts(mask=np.asarray(mask, dtype=bool),
+                                     orig_shape=tuple(orig_shape),
+                                     grid_shape=tuple(grid.data.shape),
+                                     unit=grid.unit, sz_block=sz_block,
+                                     subblocks=subblocks,
+                                     results=enc.results,
+                                     codebook=enc.codebook)
         return LevelResult(strategy=strategy, algorithm=algorithm, she=True,
                            payload_bits=enc.payload_bits,
                            codebook_bits=enc.codebook_bits,

@@ -106,7 +106,7 @@ def _shared_entropy_stage(results: list[SZResult], *, use_zstd: bool,
     materialized when a zstd pass will actually consume it.
     """
     with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("entropy"),
-                    "entropy"):
+                    "entropy", "layer.compress.entropy"):
         all_codes = (np.concatenate([r.codes for r in results])
                      if results else np.zeros(0, dtype=np.int64))
         symbols, freqs = aggregate_histogram(all_codes, engine=engine)
@@ -198,15 +198,19 @@ def she_encode(bricks: list[np.ndarray], eb: float, *, block: int = 6,
     if batched:
         results: list[SZResult | None] = [None] * len(bricks)
         groups: dict[tuple[int, ...], list[int]] = {}
-        for i, brk in enumerate(bricks):
-            brk = np.asarray(brk)
-            if brk.ndim == 3:
-                groups.setdefault(brk.shape, []).append(i)
-            else:  # rare 4D bricks keep the reference per-brick path
-                results[i] = compress_lor_reg(brk, eb, block=block,
-                                              count_entropy=False)
+        with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("gather"),
+                        "gather", "layer.compress.gather"):
+            for i, brk in enumerate(bricks):
+                brk = np.asarray(brk)
+                if brk.ndim == 3:
+                    groups.setdefault(brk.shape, []).append(i)
+                else:  # rare 4D bricks keep the reference per-brick path
+                    results[i] = compress_lor_reg(brk, eb, block=block,
+                                                  count_entropy=False)
         for shape, idxs in groups.items():
-            stack = np.stack([np.asarray(bricks[i]) for i in idxs])
+            with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("gather"),
+                            "gather", "layer.compress.gather"):
+                stack = np.stack([np.asarray(bricks[i]) for i in idxs])
             for i, r in zip(idxs, compress_lor_reg_batched(
                     stack, eb, block=block, engine=lorenzo_engine)):
                 results[i] = r

@@ -706,7 +706,7 @@ def compress_lor_reg_batched(x: np.ndarray, eb: float, *, block: int = 6,
 
     # --- Lorenzo branch: zero-halo dual-quant Lorenzo per brick ------------
     with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("prequant"),
-                    "prequant"):
+                    "prequant", "layer.compress.prequant"):
         if engine == "auto":
             from repro.device import tpu_attached
             engine = "pallas" if tpu_attached() else "numpy"
@@ -722,7 +722,7 @@ def compress_lor_reg_batched(x: np.ndarray, eb: float, *, block: int = 6,
     # Degenerate b == 1 (zero coordinate variance → NaN betas) can never
     # beat Lorenzo; skip the fit, matching the sequential path.
     with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("branch_score"),
-                    "branch_score"):
+                    "branch_score", "layer.compress.branch_score"):
         cost_lor = _code_cost_bits_rows(codes_lor)
         n_blocks = 0
         if b >= 2:
@@ -736,38 +736,43 @@ def compress_lor_reg_batched(x: np.ndarray, eb: float, *, block: int = 6,
             use_reg = np.zeros(n, dtype=bool)
 
     # --- per-brick branch choice: reconstruct only the winning branch ------
-    recon = np.empty(x.shape, dtype=np.float32)
-    lor_idx = np.flatnonzero(~use_reg)
-    reg_idx = np.flatnonzero(use_reg)
-    if lor_idx.size:
-        # recon always goes through the float64 host dequant — the same
-        # arithmetic decode_codes replays — so a container written from
-        # kernel-produced codes round-trips bit-identically on any backend
-        # (the kernel accelerates the codes hot loop; dequant is cheap)
-        recon[lor_idx] = dequant(
-            lorenzo_nd_recon(codes_lor[lor_idx], axes=(1, 2, 3)), eb)
-    if reg_idx.size:
-        bx, by, bz = bgrid
-        rr = (fit[reg_idx] + 2.0 * eb * codes_reg[reg_idx]).astype(np.float32)
-        rr = (rr.reshape(len(reg_idx), bx, by, bz, b, b, b)
-                .transpose(0, 1, 4, 2, 5, 3, 6)
-                .reshape(len(reg_idx), bx * b, by * b, bz * b))
-        recon[reg_idx] = rr[(slice(None),)
-                            + tuple(slice(0, s) for s in bshape)]
+    with obsm.timed(obsm.COMPRESS_STAGE_SECONDS.labels("recon"), "recon",
+                    "layer.compress.recon"):
+        recon = np.empty(x.shape, dtype=np.float32)
+        lor_idx = np.flatnonzero(~use_reg)
+        reg_idx = np.flatnonzero(use_reg)
+        if lor_idx.size:
+            # recon always goes through the float64 host dequant — the
+            # same arithmetic decode_codes replays — so a container
+            # written from kernel-produced codes round-trips
+            # bit-identically on any backend (the kernel accelerates the
+            # codes hot loop; dequant is cheap)
+            recon[lor_idx] = dequant(
+                lorenzo_nd_recon(codes_lor[lor_idx], axes=(1, 2, 3)), eb)
+        if reg_idx.size:
+            bx, by, bz = bgrid
+            rr = (fit[reg_idx]
+                  + 2.0 * eb * codes_reg[reg_idx]).astype(np.float32)
+            rr = (rr.reshape(len(reg_idx), bx, by, bz, b, b, b)
+                    .transpose(0, 1, 4, 2, 5, 3, 6)
+                    .reshape(len(reg_idx), bx * b, by * b, bz * b))
+            recon[reg_idx] = rr[(slice(None),)
+                                + tuple(slice(0, s) for s in bshape)]
 
-    out: list[SZResult] = []
-    for i in range(n):
-        if use_reg[i]:
-            out.append(SZResult(
-                recon=recon[i], codes=codes_reg[i].ravel().copy(),
-                payload_bits=0, codebook_bits=0,
-                meta_bits=_DIM_META_BITS + 1 + n_blocks * 4 * 32, eb=eb,
-                method="lor_reg/reg",
-                extras={"betas": betas[i], "branch": "reg"}))
-        else:
-            out.append(SZResult(
-                recon=recon[i], codes=codes_lor[i].ravel().copy(),
-                payload_bits=0, codebook_bits=0,
-                meta_bits=_DIM_META_BITS + 1, eb=eb,
-                method="lor_reg/lorenzo", extras={"branch": "lorenzo"}))
+        out: list[SZResult] = []
+        for i in range(n):
+            if use_reg[i]:
+                out.append(SZResult(
+                    recon=recon[i], codes=codes_reg[i].ravel().copy(),
+                    payload_bits=0, codebook_bits=0,
+                    meta_bits=_DIM_META_BITS + 1 + n_blocks * 4 * 32,
+                    eb=eb,
+                    method="lor_reg/reg",
+                    extras={"betas": betas[i], "branch": "reg"}))
+            else:
+                out.append(SZResult(
+                    recon=recon[i], codes=codes_lor[i].ravel().copy(),
+                    payload_bits=0, codebook_bits=0,
+                    meta_bits=_DIM_META_BITS + 1, eb=eb,
+                    method="lor_reg/lorenzo", extras={"branch": "lorenzo"}))
     return out

@@ -288,7 +288,7 @@ class TACZReader:
                 raise ValueError(f"unknown payload codec {sb.codec}")
         if huff:
             with obsm.timed(obsm.ENTROPY_DECODE_SECONDS.labels(),
-                            "entropy_decode"):
+                            "entropy_decode", "layer.reader.entropy_decode"):
                 decoded = entropy.get_engine(self._entropy_engine). \
                     decode_payloads(self._codebook(li),
                                     [payload for _, payload in huff])

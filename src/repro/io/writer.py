@@ -465,7 +465,7 @@ class TACZWriter:
             if self._err is not None:
                 raise self._err
             with obsm.timed(obsm.WRITER_LEVEL_SECONDS.labels("publish"),
-                            "publish"):
+                            "publish", "layer.writer.publish"):
                 t0 = time.perf_counter()
                 index = fmt.pack_index(self._entries)
                 self._f.write(index)
@@ -544,7 +544,7 @@ class TACZWriter:
         _, data, mask, eb, ratio, unit = item
         d = self._defaults
         with obsm.timed(obsm.WRITER_LEVEL_SECONDS.labels("encode"),
-                        "encode"):
+                        "encode", "layer.writer.encode"):
             t0 = time.perf_counter()
             lr = compress_level(data, mask, eb=eb, unit=unit,
                                 algorithm=d["algorithm"], she=d["she"],
@@ -558,7 +558,8 @@ class TACZWriter:
             return lr
 
     def _append_level(self, lr: LevelResult) -> None:
-        with obsm.timed(obsm.WRITER_LEVEL_SECONDS.labels("pack"), "pack"):
+        with obsm.timed(obsm.WRITER_LEVEL_SECONDS.labels("pack"), "pack",
+                        "layer.writer.pack"):
             t0 = time.perf_counter()
             blob, entry = pack_level(lr, payload_codec=self._payload_codec,
                                      entropy_engine=self._entropy_engine)

@@ -6,8 +6,11 @@ Three pieces, all stdlib-only:
     counters, gauges, and fixed-bucket histograms, rendering Prometheus
     text exposition and estimating quantiles from the buckets.
   * :mod:`repro.obs.trace` — a ``Span``/``trace()`` context-manager API
-    for nested per-stage timings, plus request IDs and the
-    ``X-Repro-Request-Id`` header name.
+    for nested per-stage timings, profiler annotations
+    (``annotate``: ``layer.<component>.<stage>`` regions in a JAX device
+    trace, opened only when jax is already imported and a profiler
+    records), plus request IDs and the ``X-Repro-Request-Id`` header
+    name.
   * :mod:`repro.obs.metrics` — the process-wide default ``REGISTRY``
     and the metric catalog every instrumented component records into.
   * :mod:`repro.obs.expo` — parser for the Prometheus text exposition;
@@ -32,14 +35,14 @@ from .metrics import REGISTRY, is_enabled, set_enabled, timed
 from .registry import (DEFAULT_TIME_BUCKETS, Counter, Gauge, Histogram,
                        MetricsRegistry, quantile_from_buckets)
 from .slo import RULE_TYPES, SLOEngine, SLORule
-from .trace import (REQUEST_ID_HEADER, Span, current_span, new_request_id,
-                    root_span, trace)
+from .trace import (REQUEST_ID_HEADER, Span, annotate, current_span,
+                    new_request_id, root_span, trace)
 from . import expo
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "DEFAULT_TIME_BUCKETS", "quantile_from_buckets",
-    "Span", "trace", "root_span", "current_span",
+    "Span", "trace", "root_span", "current_span", "annotate",
     "new_request_id", "REQUEST_ID_HEADER",
     "REGISTRY", "metrics", "set_enabled", "is_enabled", "timed",
     "expo", "ParsedFamily", "ParsedHistogram",

@@ -218,7 +218,7 @@ def parse(text: str) -> dict[str, ParsedFamily]:
             fam = families[name] = ParsedFamily(name)
         return fam
 
-    for raw in text.splitlines():
+    for raw in text.split("\n"):
         line = raw.strip()
         if not line:
             continue

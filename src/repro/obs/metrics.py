@@ -16,12 +16,18 @@ The catalog below is the single source of truth for metric names; the
 choices: request/stage latencies share :data:`~repro.obs.registry.
 DEFAULT_TIME_BUCKETS` (100 µs–10 s) so quantiles are comparable across
 stages.
+
+Importing this module installs one ``gc.callbacks`` hook per process
+that times every collection into ``tacz_gc_pause_seconds``.
 """
 from __future__ import annotations
 
+import collections
+import gc
 import time
 
 from .registry import DEFAULT_TIME_BUCKETS, MetricsRegistry
+from .trace import _NULL, annotate
 from .trace import trace as _trace
 
 __all__ = [
@@ -30,8 +36,9 @@ __all__ = [
     "DEVICE_ITEMS", "HOST_FALLBACKS",
     "WRITER_LEVEL_SECONDS", "WRITER_BYTES", "WRITER_LEVELS",
     "PLANNER_SUBBLOCKS", "PLANNER_DECODE_SECONDS", "PLANNER_DECODED_BYTES",
-    "ENTROPY_DECODE_SECONDS",
-    "SERVER_REQUEST_SECONDS", "SERVER_REGIONS",
+    "ENTROPY_DECODE_SECONDS", "ENTROPY_DECODE_STAGE_SECONDS",
+    "SERVER_REQUEST_SECONDS", "SERVER_STAGE_SECONDS", "GC_PAUSE_SECONDS",
+    "SERVER_REGIONS",
     "SERVER_BACKPRESSURE", "SERVER_DECODE_UNITS", "SERVER_QUEUE_DEPTH",
     "CACHE_HITS", "CACHE_MISSES", "CACHE_EVICTIONS",
     "CACHE_ENTRIES", "CACHE_BYTES", "CACHE_BUDGET_BYTES",
@@ -63,20 +70,28 @@ def is_enabled() -> bool:
 
 class timed:
     """Time a region into a histogram child — and, when a root span is
-    active on this thread, into a same-named trace span too.
+    active on this thread, into a same-named trace span too — and, with
+    ``layer``, mark it in the profiler's trace (see
+    :func:`repro.obs.trace.annotate`).
 
-    ``with timed(WRITER_LEVEL_SECONDS.labels("encode"), "encode"): ...``
-    is the one instrumentation idiom the hot paths use: the metric feeds
-    the scrape surface, the span feeds per-request response metadata.
-    The trace half is the shared no-op outside a root span, and the
-    histogram's ``observe`` is a no-op when the registry is disabled.
+    ``with timed(WRITER_LEVEL_SECONDS.labels("encode"), "encode",
+    "layer.writer.encode"): ...`` is the one instrumentation idiom the
+    hot paths use: the metric feeds the scrape surface, the span feeds
+    per-request response metadata, the annotation names the stage in a
+    device trace.  The trace half is the shared no-op outside a root
+    span, the annotation the shared no-op while no profiler records, and
+    the histogram's ``observe`` is a no-op when the registry is disabled.
     """
 
     __slots__ = ("_hist", "_span", "_t0")
 
-    def __init__(self, hist_child, span_name: str | None = None):
+    def __init__(self, hist_child, span_name: str | None = None,
+                 layer: str | None = None):
         self._hist = hist_child
-        self._span = _trace(span_name) if span_name else None
+        if span_name:
+            self._span = _trace(span_name, layer)
+        else:
+            self._span = annotate(layer) if layer else None
         self._t0 = 0.0
 
     def __enter__(self) -> "timed":
@@ -95,8 +110,8 @@ class timed:
 
 COMPRESS_STAGE_SECONDS = REGISTRY.histogram(
     "tacz_compress_stage_seconds",
-    "Per-stage wall time inside compress_level "
-    "(stage: prequant | branch_score | entropy).",
+    "Per-stage wall time inside compress_level (stage: partition | "
+    "gather | prequant | branch_score | recon | entropy).",
     labels=("stage",))
 
 COMPRESS_LEVEL_SECONDS = REGISTRY.histogram(
@@ -162,11 +177,23 @@ ENTROPY_DECODE_SECONDS = REGISTRY.histogram(
     "Wall time of EntropyEngine payload-decode launches inside "
     "TACZReader.decode_subblocks.")
 
+ENTROPY_DECODE_STAGE_SECONDS = REGISTRY.histogram(
+    "tacz_entropy_decode_stage_seconds",
+    "Per-stage wall time inside EntropyEngine.decode_payloads (stage: "
+    "pack | device | unpack | host).",
+    labels=("stage",))
+
 # ------------------------------- server ----------------------------------
 
 SERVER_REQUEST_SECONDS = REGISTRY.histogram(
     "tacz_server_request_seconds",
     "End-to-end RegionServer.get_regions latency per batch.")
+
+SERVER_STAGE_SECONDS = REGISTRY.histogram(
+    "tacz_server_stage_seconds",
+    "Per-stage wall time of the serving path (stage: queue_wait | plan | "
+    "recon | assemble).",
+    labels=("stage",))
 
 SERVER_REGIONS = REGISTRY.counter(
     "tacz_server_regions_total",
@@ -310,6 +337,51 @@ VARIANT_UNSATISFIED = REGISTRY.counter(
     "tacz_variant_unsatisfied_total",
     "Distortion-target requests rejected because no variant satisfies "
     "the target (HTTP 400).")
+
+# ------------------------------- runtime ----------------------------------
+
+GC_PAUSE_SECONDS = REGISTRY.histogram(
+    "tacz_gc_pause_seconds",
+    "Wall time of each Python garbage collection in the process, by the "
+    "generation collected (0 | 1 | 2).",
+    labels=("generation",))
+
+
+class _GCPauses:
+    """The ``gc.callbacks`` hook behind ``tacz_gc_pause_seconds``.
+
+    A collection may start while its thread holds the histogram's lock
+    (a scrape copying the series), so a pause the lock turns away waits
+    in a queue for the next collection instead of blocking.  While a
+    profiler records, each collection is also a ``layer.gc.collect``
+    annotation.
+    """
+
+    def __init__(self):
+        self._children = [GC_PAUSE_SECONDS.labels(str(g)) for g in range(3)]
+        self._waiting: collections.deque = collections.deque()
+        self._t0 = 0.0
+        self._ann = _NULL
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._ann = annotate("layer.gc.collect")
+            self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(None, None, None)
+        self._ann = _NULL
+        self._waiting.append((info["generation"], dt))
+        while self._waiting:
+            g, d = self._waiting[0]
+            if not self._children[g].try_observe(d):
+                return
+            self._waiting.popleft()
+
+
+_GC_HOOK = _GCPauses()
+gc.callbacks.append(_GC_HOOK)
 
 # --------------------------------- slo ------------------------------------
 # The SLO engine (repro.obs.slo) exports its alert state back into the

@@ -201,11 +201,7 @@ class Histogram(_Child):
         self._sum = 0.0
         self._count = 0
 
-    def observe(self, value: float) -> None:
-        """Record one sample."""
-        if not self._reg.enabled:
-            return
-        v = float(value)
+    def _bucket(self, v: float) -> int:
         # linear scan: bucket lists are short (≤ ~16) and almost every
         # latency sample lands in the first few buckets — cheaper than
         # bisect's function-call overhead at this size
@@ -214,10 +210,35 @@ class Histogram(_Child):
         n = len(bounds)
         while i < n and v > bounds[i]:
             i += 1
+        return i
+
+    def _add(self, v: float) -> None:
+        """Record ``v``; the caller holds the lock."""
+        self._counts[self._bucket(v)] += 1
+        self._sum += v
+        self._count += 1
+
+    def observe(self, value: float) -> None:
+        """Record one sample."""
+        if not self._reg.enabled:
+            return
+        v = float(value)
         with self._lock:
-            self._counts[i] += 1
-            self._sum += v
-            self._count += 1
+            self._add(v)
+
+    def try_observe(self, value: float) -> bool:
+        """:meth:`observe` unless the family's lock is held (by any
+        thread, this one included): then record nothing and return False.
+        For callers that may run while this thread holds the lock, such
+        as a garbage-collector callback."""
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
+            if self._reg.enabled:
+                self._add(float(value))
+        finally:
+            self._lock.release()
+        return True
 
     @property
     def count(self) -> int:

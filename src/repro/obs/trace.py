@@ -15,6 +15,16 @@ summaries explicitly — e.g. ``ShardedRegionRouter`` opens one root per
 batch, runs each shard group under its own root *in the pool thread*,
 and grafts the finished summaries back into the batch root.
 
+Profiler annotations: ``trace(name, layer=...)`` (and
+``metrics.timed``) also open a profiler annotation named ``layer`` —
+``jax.profiler.TraceAnnotation``, by convention
+``layer.<component>.<stage>`` — around the same region, so the
+program's stages share the device trace's clock and label its idle
+gaps.  The profiler is reached only once ``jax`` is imported by someone
+else (this module never imports it), and an annotation is opened only
+while a profiler session records: otherwise :func:`annotate` returns
+the shared no-op, for one flag check.
+
 Request IDs (:func:`new_request_id`) are 16 hex chars from
 ``os.urandom`` — unique enough to grep a fleet's access logs, cheap
 enough to mint per batch.  They ride the :data:`REQUEST_ID_HEADER`
@@ -23,10 +33,11 @@ HTTP header from router to shards.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 
-__all__ = ["Span", "trace", "root_span", "current_span",
+__all__ = ["Span", "trace", "root_span", "current_span", "annotate",
            "new_request_id", "REQUEST_ID_HEADER"]
 
 #: HTTP header carrying the request ID from router to shard (and echoed
@@ -143,12 +154,63 @@ def root_span(name: str) -> _RootCtx:
     return _RootCtx(Span(name))
 
 
-def trace(name: str):
+#: ``jax.profiler.TraceAnnotation`` once resolved (None until jax is
+#: imported)
+_annotation_cls = None
+
+
+def _profiler_annotation():
+    """The profiler's annotation class, or None until ``jax.profiler`` is
+    fully imported by someone else.  Nothing is imported here: that would
+    pull jax into every ``repro.obs`` user, and this runs inside
+    garbage-collector callbacks, which may fire halfway through jax's own
+    import."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        _annotation_cls = getattr(sys.modules.get("jax.profiler"),
+                                  "TraceAnnotation", None)
+    return _annotation_cls
+
+
+def annotate(layer: str):
+    """A profiler annotation named ``layer`` while a profiler session
+    records, else the shared no-op."""
+    cls = _annotation_cls or _profiler_annotation()
+    if cls is not None and cls.is_enabled():
+        return cls(layer)
+    return _NULL
+
+
+class _Annotated:
+    """A span (or the no-op) inside a profiler annotation."""
+
+    __slots__ = ("_span", "_ann")
+
+    def __init__(self, span, ann):
+        self._span = span
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        try:
+            self._span.__exit__(*exc)
+        finally:
+            self._ann.__exit__(*exc)
+
+
+def trace(name: str, layer: str | None = None):
     """A child span of the active span on this thread — or a shared
-    no-op when no root is active (the common, uninstrumented case)."""
-    if getattr(_local, "span", None) is None:
-        return _NULL
-    return Span(name)
+    no-op when no root is active (the common, uninstrumented case).
+    With ``layer``, the region is also a profiler annotation of that
+    name (see :func:`annotate`)."""
+    span = _NULL if getattr(_local, "span", None) is None else Span(name)
+    if layer is None:
+        return span
+    ann = annotate(layer)
+    return span if ann is _NULL else _Annotated(span, ann)
 
 
 def current_span() -> Span | None:

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import math
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 
 from repro import obs
@@ -165,12 +166,18 @@ class AsyncServingCore:
             return [levels]
         return [[li] for li in levels]
 
-    def _run_unit(self, boxes, levels, target, variant):
+    def _run_unit(self, t_submit, started, boxes, levels, target, variant):
         """One decode unit on a pool thread.  Returns ``(crc, variant,
         results, spans)`` with the unit's finished trace spans collected
-        for grafting (pool threads do not inherit the caller's root)."""
+        for grafting (pool threads do not inherit the caller's root).
+        The wait since ``t_submit`` is the unit's ``queue_wait``;
+        ``started`` tells the submitting thread that the wait is over."""
+        obsm.SERVER_STAGE_SECONDS.labels("queue_wait").observe(
+            time.perf_counter() - t_submit)
+        started.set()
         obsm.SERVER_DECODE_UNITS.inc()
-        with obs.root_span("decode_unit") as root:
+        with obs.annotate("layer.server.unit"), \
+                obs.root_span("decode_unit") as root:
             if target is None and variant is None:
                 crc, results = self.server.get_regions_with_crc(
                     boxes, levels=levels)
@@ -198,13 +205,20 @@ class AsyncServingCore:
             units = self._unit_levels(levels, target, variant)
             self._admit(len(units))
             futs = []
+            started = [threading.Event() for _ in units]
             try:
                 try:
-                    for u in units:
+                    for u, ev in zip(units, started):
                         futs.append(self._pool.submit(
-                            self._run_unit, boxes, u, target, variant))
+                            self._run_unit, time.perf_counter(), ev, boxes,
+                            u, target, variant))
                 except RuntimeError:   # pool shut down after admission
                     self._reject("draining")
+                # the profiler shows the wait for a decode worker on the
+                # submitting thread
+                with obs.annotate("layer.server.queue_wait"):
+                    for ev in started:
+                        ev.wait()
                 outs = [f.result() for f in futs]
             finally:
                 self._release(len(units))

@@ -357,7 +357,8 @@ class DecodePlanner:
         obsm.PLANNER_SUBBLOCKS.labels("cached").inc(len(out))
         obsm.PLANNER_SUBBLOCKS.labels("decoded").inc(len(missing))
         decoded_bytes = 0
-        with obsm.timed(obsm.PLANNER_DECODE_SECONDS.labels(), "decode"):
+        with obsm.timed(obsm.PLANNER_DECODE_SECONDS.labels(), "decode",
+                        "layer.planner.decode"):
             # gsp/global levels: single global payload each — decode
             # serially
             by_level: dict[int, list[int]] = {}
@@ -381,18 +382,22 @@ class DecodePlanner:
                     groups.setdefault((rd.subblock_shape(li, sbi),
                                        e.subblocks[sbi].branch),
                                       []).append(sbi)
-                for (shape, branch), g in groups.items():
-                    codes = np.stack([decoded[sbi][0] for sbi in g])
-                    betas = (np.stack([decoded[sbi][1] for sbi in g])
-                             if branch == fmt.BRANCH_REG else None)
-                    recon = sz.decode_codes_batched(
-                        codes, shape, e.eb, branch=fmt.BRANCH_NAMES[branch],
-                        block=e.sz_block, betas=betas)
-                    for sbi, brick in zip(g, recon):
-                        brick = brick.copy()   # detach from the stacked batch
-                        cache.put((gen, li, sbi), brick)
-                        out[(li, sbi)] = brick
-                        decoded_bytes += brick.nbytes
+                with obsm.timed(obsm.SERVER_STAGE_SECONDS.labels("recon"),
+                                layer="layer.server.recon"):
+                    for (shape, branch), g in groups.items():
+                        codes = np.stack([decoded[sbi][0] for sbi in g])
+                        betas = (np.stack([decoded[sbi][1] for sbi in g])
+                                 if branch == fmt.BRANCH_REG else None)
+                        recon = sz.decode_codes_batched(
+                            codes, shape, e.eb,
+                            branch=fmt.BRANCH_NAMES[branch],
+                            block=e.sz_block, betas=betas)
+                        for sbi, brick in zip(g, recon):
+                            # detach from the stacked batch
+                            brick = brick.copy()
+                            cache.put((gen, li, sbi), brick)
+                            out[(li, sbi)] = brick
+                            decoded_bytes += brick.nbytes
         obsm.PLANNER_DECODED_BYTES.inc(decoded_bytes)
         return out
 
@@ -611,7 +616,7 @@ class RegionServer:
         with self._lock:
             rd, planner = self._reader, self._planner
             self._inflight[id(rd)] = self._inflight.get(id(rd), 0) + 1
-        span = obs.trace("get_regions")
+        span = obs.trace("get_regions", "layer.server.get_regions")
         span.__enter__()
         t0 = time.perf_counter()
         try:
@@ -626,7 +631,8 @@ class RegionServer:
                     raise ValueError(f"level {li} out of range "
                                      f"(0..{rd.n_levels - 1})")
             queries = [(li, box) for box in boxes for li in lis]
-            with obs.trace("plan"):
+            with obsm.timed(obsm.SERVER_STAGE_SECONDS.labels("plan"), "plan",
+                            "layer.server.plan"):
                 plans = planner.plan(queries)
             bricks = planner.fetch(plans, self.cache)
 
@@ -638,25 +644,27 @@ class RegionServer:
 
             out: list[list[ROILevel]] = []
             it = iter(plans)
-            for _ in boxes:
-                per_box: list[ROILevel] = []
-                for li in lis:
-                    p = next(it)
-                    if not p.owned:   # foreign whole-level key: zeros —
-                        # the router overlays the owning shard's crop
-                        data = np.zeros(tuple(max(hi - lo, 0)
-                                              for lo, hi in p.lbox),
-                                        dtype=np.float32)
-                    else:
-                        data = rd.assemble_level_roi(p.level, p.lbox,
-                                                     fetch_brick,
-                                                     fetch_level,
-                                                     tasks=p.tasks)
-                    per_box.append(ROILevel(
-                        level=p.level,
-                        ratio=max(int(rd.levels[p.level].ratio), 1),
-                        box=p.lbox, data=data))
-                out.append(per_box)
+            with obsm.timed(obsm.SERVER_STAGE_SECONDS.labels("assemble"),
+                            layer="layer.server.assemble"):
+                for _ in boxes:
+                    per_box: list[ROILevel] = []
+                    for li in lis:
+                        p = next(it)
+                        if not p.owned:   # foreign whole-level key: zeros
+                            # — the router overlays the owning shard's crop
+                            data = np.zeros(tuple(max(hi - lo, 0)
+                                                  for lo, hi in p.lbox),
+                                            dtype=np.float32)
+                        else:
+                            data = rd.assemble_level_roi(p.level, p.lbox,
+                                                         fetch_brick,
+                                                         fetch_level,
+                                                         tasks=p.tasks)
+                        per_box.append(ROILevel(
+                            level=p.level,
+                            ratio=max(int(rd.levels[p.level].ratio), 1),
+                            box=p.lbox, data=data))
+                    out.append(per_box)
             return rd.index_crc, out
         finally:
             span.__exit__(None, None, None)
