@@ -855,6 +855,7 @@ class MultiPartReader(TACZReader):
         self._codebooks = {}
         self._masks = {}
         self._io_lock = threading.Lock()
+        self._bounds = self._subblock_bounds()
         self._parts: list[TACZReader | None] = [None] * len(self._part_names)
         self._parts_lock = threading.Lock()
 

@@ -142,8 +142,24 @@ class TACZReader:
             raise
         self._codebooks: dict[int, huffman.Codebook] = {}
         self._masks: dict[int, np.ndarray | None] = {}
+        self._bounds = self._subblock_bounds()
 
     # ------------------------------ plumbing -------------------------------
+
+    def _subblock_bounds(self) -> list[tuple[np.ndarray, np.ndarray]]:
+        """Per level, read-only ``(lo, hi)`` int64 arrays of shape
+        ``(N, 3)``: each sub-block's ``origin`` and ``origin + size``, in
+        index order.  They are column-major, so the per-dimension tests
+        of :meth:`intersecting_subblocks` run over contiguous columns."""
+        out = []
+        for e in self.levels:
+            geo = np.array([sb.origin + sb.size for sb in e.subblocks],
+                           dtype=np.int64).reshape(-1, 6)
+            lo = np.asfortranarray(geo[:, :3])
+            hi = np.asfortranarray(geo[:, :3] + geo[:, 3:])
+            lo.flags.writeable = hi.flags.writeable = False
+            out.append((lo, hi))
+        return out
 
     def close(self) -> None:
         """Close the underlying handle (no-op for caller-owned files)."""
@@ -465,20 +481,36 @@ class TACZReader:
                                ) -> list[tuple[int, Box]]:
         """Sub-blocks of level ``li`` whose cuboids overlap ``lbox``.
 
+        The lookup is a vectorised overlap test over the level's
+        sub-block bounds: two read-only ``int64`` arrays of shape
+        ``(N, 3)``, each sub-block's ``origin`` and ``origin + size``,
+        built once from the parsed index when the reader opens and kept
+        for the reader's lifetime (a hot-swapped snapshot's new reader
+        builds its own).  Only the hits come back to Python, so the work
+        past the test is O(hits), not O(N).
+
         :param li: level index.
         :param lbox: three half-open ranges in *level* cells.
         :returns: ``(sub_block_index, intersection_box)`` pairs in index
-            order; the intersection is again in level cells.
+            order, as plain ``int``; the intersection is again in level
+            cells and is non-empty in every dimension.
         """
-        e = self.levels[li]
-        out: list[tuple[int, Box]] = []
-        for i, sb in enumerate(e.subblocks):
-            isect = tuple(
-                (max(lo, o), min(hi, o + s))
-                for (lo, hi), o, s in zip(lbox, sb.origin, sb.size))
-            if all(hi > lo for lo, hi in isect):
-                out.append((i, isect))
-        return out
+        lo, hi = self._bounds[li]
+        hit = np.ones(len(lo), dtype=bool)
+        for d, (blo, bhi) in enumerate(lbox):
+            hit &= lo[:, d] < bhi
+            hit &= hi[:, d] > blo
+        idx = np.flatnonzero(hit)
+        box = np.array(lbox, dtype=np.int64)
+        ilo = np.maximum(lo[idx], box[:, 0])
+        ihi = np.minimum(hi[idx], box[:, 1])
+        # the overlap test passes an empty box or a zero-size sub-block;
+        # the intersection itself must be non-empty in every dimension
+        keep = np.all(ilo < ihi, axis=1)
+        return [(i, ((a0, b0), (a1, b1), (a2, b2)))
+                for i, (a0, a1, a2), (b0, b1, b2)
+                in zip(idx[keep].tolist(), ilo[keep].tolist(),
+                       ihi[keep].tolist())]
 
     def subblock_keys(self, levels: list[int] | None = None,
                       ) -> list[tuple[int, int]]:
@@ -571,7 +603,7 @@ class TACZReader:
         level reconstruction (gsp/global levels — their single payload is
         not block-local).  ``tasks`` may carry a precomputed
         ``intersecting_subblocks(li, lbox)`` result (the serving planner
-        already ran the scan).  ``fetch_bricks(li, [(sbi, local_hi)])``,
+        already ran the lookup).  ``fetch_bricks(li, [(sbi, local_hi)])``,
         when given, replaces the per-brick calls with one batched fetch
         for the whole SHE task list (the cold ROI path routes this at the
         batched entropy engine).  Masking and crop placement are

@@ -36,6 +36,7 @@ __all__ = [
     "DEVICE_ITEMS", "HOST_FALLBACKS",
     "WRITER_LEVEL_SECONDS", "WRITER_BYTES", "WRITER_LEVELS",
     "PLANNER_SUBBLOCKS", "PLANNER_DECODE_SECONDS", "PLANNER_DECODED_BYTES",
+    "PLANNER_INTERSECT_SECONDS", "PLANNER_INTERSECT_SUBBLOCKS",
     "ENTROPY_DECODE_SECONDS", "ENTROPY_DECODE_STAGE_SECONDS",
     "SERVER_REQUEST_SECONDS", "SERVER_STAGE_SECONDS", "GC_PAUSE_SECONDS",
     "SERVER_REGIONS",
@@ -171,6 +172,16 @@ PLANNER_DECODED_BYTES = REGISTRY.counter(
     "tacz_planner_decoded_bytes_total",
     "Decoded float32 bytes produced by DecodePlanner.fetch "
     "(cache-miss path only).")
+
+PLANNER_INTERSECT_SECONDS = REGISTRY.histogram(
+    "tacz_planner_intersect_seconds",
+    "Wall time of each SHE level box's sub-block lookup "
+    "(TACZReader.intersecting_subblocks) inside DecodePlanner.plan.")
+
+PLANNER_INTERSECT_SUBBLOCKS = REGISTRY.counter(
+    "tacz_planner_intersect_subblocks_total",
+    "Sub-blocks those lookups returned, before a shard's ownership "
+    "filter.")
 
 ENTROPY_DECODE_SECONDS = REGISTRY.histogram(
     "tacz_entropy_decode_seconds",

@@ -309,7 +309,10 @@ class DecodePlanner:
             if any(hi <= lo for lo, hi in lbox):
                 out.append(PlannedLevel(li, lbox, (), False))
             elif rd.levels[li].strategy in TACZReader._SHE_STRATEGIES:
-                tasks = rd.intersecting_subblocks(li, lbox)
+                with obsm.timed(obsm.PLANNER_INTERSECT_SECONDS.labels(),
+                                layer="layer.planner.intersect"):
+                    tasks = rd.intersecting_subblocks(li, lbox)
+                obsm.PLANNER_INTERSECT_SUBBLOCKS.inc(len(tasks))
                 if owned is not None:
                     tasks = [t for t in tasks if (li, t[0]) in owned]
                 out.append(PlannedLevel(li, lbox, tuple(tasks), False))
