@@ -226,6 +226,7 @@ def _compress_level(data: np.ndarray, mask: np.ndarray, *, eb: float,
             data, mask, unit=unit, algorithm=algorithm, she=she,
             strategy=strategy)
         sb_meta = sum(sb.meta_bits() for sb in subblocks)
+    obsm.COMPRESS_SUBBLOCKS.labels(strategy).inc(len(subblocks))
 
     orig_shape = data.shape
 

@@ -648,7 +648,8 @@ def _lorenzo_codes_batched_pallas(x: np.ndarray, eb: float) -> np.ndarray | None
     bound rather than merely differ in last-ulp rounding.  The numpy host
     path stays the bit-exact float64/int64 oracle.  Bricks go to
     ``tacz_device_items_total`` or, with the guard that stopped them, to
-    ``tacz_host_fallbacks_total``.
+    ``tacz_host_fallbacks_total``; each kernel call adds one to
+    ``tacz_device_launches_total``.
     """
     shape = tuple(int(s) for s in x.shape[1:])
     reason = None
@@ -665,6 +666,7 @@ def _lorenzo_codes_batched_pallas(x: np.ndarray, eb: float) -> np.ndarray | None
     codes = ops.lorenzo3d_codes_batched(x.astype(np.float32), eb=float(eb),
                                         tile=shape)
     obsm.DEVICE_ITEMS.labels("lorenzo", device_label(codes)).inc(x.shape[0])
+    obsm.DEVICE_LAUNCHES.labels("lorenzo").inc()
     return np.asarray(codes).astype(np.int64)
 
 

@@ -33,7 +33,8 @@ from .trace import trace as _trace
 __all__ = [
     "REGISTRY", "set_enabled", "is_enabled", "timed",
     "COMPRESS_STAGE_SECONDS", "COMPRESS_LEVEL_SECONDS",
-    "DEVICE_ITEMS", "HOST_FALLBACKS",
+    "COMPRESS_SUBBLOCKS", "DEVICE_ITEMS", "DEVICE_LAUNCHES",
+    "HOST_FALLBACKS",
     "WRITER_LEVEL_SECONDS", "WRITER_BYTES", "WRITER_LEVELS",
     "PLANNER_SUBBLOCKS", "PLANNER_DECODE_SECONDS", "PLANNER_DECODED_BYTES",
     "PLANNER_INTERSECT_SECONDS", "PLANNER_INTERSECT_SUBBLOCKS",
@@ -121,6 +122,12 @@ COMPRESS_LEVEL_SECONDS = REGISTRY.histogram(
     "strategy (gsp | opst | akdtree | nast).",
     labels=("strategy",))
 
+COMPRESS_SUBBLOCKS = REGISTRY.counter(
+    "tacz_compress_subblocks_total",
+    "Sub-blocks partition_level cut a compressed level into, labeled by "
+    "the resolved strategy (gsp levels add 0).",
+    labels=("strategy",))
+
 # ------------------------- device vs host routing --------------------------
 # The device engines (Pallas Lorenzo codes, Pallas Huffman decode) keep
 # correctness guards that send some work to the host oracles; both sides
@@ -131,6 +138,12 @@ DEVICE_ITEMS = REGISTRY.counter(
     "Work items a device kernel processed (stage: lorenzo = bricks, "
     "huffman_decode = payloads), by device (platform:id).",
     labels=("stage", "device"))
+
+DEVICE_LAUNCHES = REGISTRY.counter(
+    "tacz_device_launches_total",
+    "Device program launches (stage: lorenzo = one batched Lorenzo "
+    "kernel call over a stack of same-shape bricks).",
+    labels=("stage",))
 
 HOST_FALLBACKS = REGISTRY.counter(
     "tacz_host_fallbacks_total",
